@@ -21,14 +21,15 @@ from .handles import CollectiveHandle
 from .recorder import (EVENT_NAMES, Diagnosis, FlightEvent, StalledChain,
                        diagnose, events)
 from .primitives import CollKind, CollectiveSpec, Communicator, Prim
-from .runtime import OcclRuntime
+from .runtime import OcclRuntime, registered_heap_elems
 from .staging import StagingEngine
 from .deadlock import run_static_order, consistent_order_exists
 
 __all__ = [
     "OcclConfig", "OrderPolicy", "ReduceOp",
     "CollKind", "CollectiveSpec", "Communicator", "Prim",
-    "OcclRuntime", "DeadlockTimeout", "ConnDepthWarning", "StagingEngine",
+    "OcclRuntime", "registered_heap_elems", "DeadlockTimeout",
+    "ConnDepthWarning", "StagingEngine",
     "EvictionError", "RegistrationClosed", "StepTimeout",
     "CollectiveHandle",
     "FlightEvent", "StalledChain", "Diagnosis", "EVENT_NAMES",

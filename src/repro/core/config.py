@@ -211,7 +211,13 @@ class OcclConfig:
 
     # --- numerics / kernels ---------------------------------------------
     dtype: str = "float32"          # heap / wire dtype
-    use_pallas: bool = False        # route slice math through Pallas kernels
+    use_pallas: bool = False        # route slice math through the fused
+                                    # Pallas slice kernel (kernels/), which
+                                    # compiles natively for the TPU
+    pallas_interpret: bool = False  # run that kernel in the Pallas
+                                    # interpreter instead — the only way it
+                                    # runs off a TPU (CPU tests); never
+                                    # chosen implicitly
 
     # --- mesh-backend fast path -----------------------------------------
     packed_16bit: bool = True       # mesh backend: bitcast PAIRS of 16-bit

@@ -142,19 +142,24 @@ def _pack16_to_i32(pay: jnp.ndarray, pad: int) -> jnp.ndarray:
     ``pay`` is [G, W] of a 2-byte dtype; an odd W is zero-padded by ``pad``
     (0 or 1) so every element has a pair partner.  Returns [G, (W+pad)//2]
     i32 — exact bits, concatenable with the i32 (coll, count) header.
+    The payload is bitcast to u16 FIRST: a pad/concatenate in a float
+    dtype may canonicalize NaN payloads (0x7f81 -> 0x7fc0 in bf16), while
+    integer data movement carries every bit pattern.
     """
+    bits = jax.lax.bitcast_convert_type(pay, jnp.uint16)
     if pad:
-        pay = jnp.concatenate(
-            [pay, jnp.zeros((pay.shape[0], pad), pay.dtype)], axis=1)
+        bits = jnp.concatenate(
+            [bits, jnp.zeros((bits.shape[0], pad), jnp.uint16)], axis=1)
     return jax.lax.bitcast_convert_type(
-        pay.reshape(pay.shape[0], -1, 2), jnp.int32)
+        bits.reshape(bits.shape[0], -1, 2), jnp.int32)
 
 
 def _unpack16_from_i32(packed: jnp.ndarray, dtype, width: int) -> jnp.ndarray:
     """Inverse of :func:`_pack16_to_i32`: [G, P] i32 -> [G, width] 16-bit
-    (the pad element, if any, is sliced off)."""
-    pairs = jax.lax.bitcast_convert_type(packed, dtype)   # [G, P, 2]
-    return pairs.reshape(pairs.shape[0], -1)[:, :width]
+    (the pad element, if any, is sliced off in the u16 domain)."""
+    pairs = jax.lax.bitcast_convert_type(packed, jnp.uint16)   # [G, P, 2]
+    bits = pairs.reshape(pairs.shape[0], -1)[:, :width]
+    return jax.lax.bitcast_convert_type(bits, dtype)
 
 
 def _mesh_exchange(t: StaticTables, outbox: Mailbox, axis_name: str) -> Mailbox:
@@ -420,7 +425,9 @@ def _sim_daemon_jit(cfg: OcclConfig, edges: tuple = ()) -> Callable:
 
     tick = _sim_tick_fn(cfg, edges, barrier=True)
 
-    @jax.jit
+    # The state is donated: a launch rewrites it in place, so the device
+    # never holds two copies of the heaps (callers re-read ``rt.state``).
+    @functools.partial(jax.jit, donate_argnums=(4,))
     def daemon(sh: SharedTables, lt: LocalTables, fwd_src, rev_src,
                st: DaemonState) -> DaemonState:
         # A launch IS prologue + one barrier tick.  k = budget + 1 never
@@ -484,7 +491,6 @@ def build_shardmap_daemon(cfg: OcclConfig, t: StaticTables, mesh,
     sharded along ``axis_name``; each device runs the per-rank scheduler
     and the connector fabric is a ppermute pair per lane per superstep."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh_daemon = build_mesh_daemon(cfg, t, axis_name)
 
@@ -493,10 +499,11 @@ def build_shardmap_daemon(cfg: OcclConfig, t: StaticTables, mesh,
         st1 = mesh_daemon(st1)
         return jax.tree_util.tree_map(lambda a: a[None], st1)
 
-    inner = shard_map(per_dev, mesh=mesh, in_specs=P(axis_name),
-                      out_specs=P(axis_name), check_rep=False)
+    inner = jax.shard_map(per_dev, mesh=mesh, in_specs=P(axis_name),
+                          out_specs=P(axis_name), check_vma=False)
 
-    @jax.jit
+    # Donated state, as in the sim daemon.
+    @functools.partial(jax.jit, donate_argnums=(0,))
     def daemon(st: DaemonState) -> DaemonState:
         return inner(st)
 
@@ -529,7 +536,6 @@ def count_exchange_ppermutes(cfg: OcclConfig, n_comms: int = 1) -> int:
     import dataclasses as _dc
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from .primitives import Communicator
     from .tables import build_tables
 
@@ -553,8 +559,8 @@ def count_exchange_ppermutes(cfg: OcclConfig, n_comms: int = 1) -> int:
         out = _mesh_exchange(t, ob1, "rank")
         return jax.tree_util.tree_map(lambda a: a[None], out)
 
-    fn = shard_map(per_dev, mesh=mesh, in_specs=P("rank"),
-                   out_specs=P("rank"), check_rep=False)
+    fn = jax.shard_map(per_dev, mesh=mesh, in_specs=P("rank"),
+                       out_specs=P("rank"), check_vma=False)
     closed = jax.make_jaxpr(fn)(outbox)
     return _count_primitive(closed.jaxpr, "ppermute")
 
@@ -639,7 +645,6 @@ def build_shardmap_tick(cfg: OcclConfig, t: StaticTables, mesh,
     ``k`` and the returned flags are replicated.  NOT jitted — compose it
     inside a jitted step or wrap in ``jax.jit`` for host use."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh_tick = build_mesh_tick(cfg, t, axis_name, rank_of_device,
                                 barrier=barrier)
@@ -649,5 +654,5 @@ def build_shardmap_tick(cfg: OcclConfig, t: StaticTables, mesh,
         st1, flags = mesh_tick(st1, k)
         return jax.tree_util.tree_map(lambda a: a[None], st1), flags
 
-    return shard_map(per_dev, mesh=mesh, in_specs=(P(axis_name), P()),
-                     out_specs=(P(axis_name), P()), check_rep=False)
+    return jax.shard_map(per_dev, mesh=mesh, in_specs=(P(axis_name), P()),
+                         out_specs=(P(axis_name), P()), check_vma=False)

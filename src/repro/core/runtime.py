@@ -60,6 +60,20 @@ from .state import DaemonState, init_state
 from .tables import StaticTables, build_tables
 
 
+def registered_heap_elems(cfg: OcclConfig,
+                          register: Callable[["OcclRuntime"], None]) -> int:
+    """Heap elements per arena that ``register(runtime)`` allocates.
+
+    The registrations run on a probe runtime, which touches no device:
+    registration only advances the in/out arena pointers, and state is
+    built at the first launch.  The larger pointer sizes both arenas
+    (``cfg.heap_elems``); the scheduler's burst scratch is added on top
+    by ``state.heap_scratch_elems``."""
+    probe = OcclRuntime(dataclasses.replace(cfg, heap_elems=1 << 62))
+    register(probe)
+    return max(probe._in_ptr, probe._out_ptr, 1)
+
+
 class OcclRuntime:
     def __init__(self, cfg: OcclConfig, mesh=None, mesh_axis: str = "rank",
                  cost_model=None):
@@ -70,7 +84,12 @@ class OcclRuntime:
         registration; None loads the persisted calibration lazily
         (BENCH_calibration.json / REPRO_CALIBRATION)."""
         self.cfg = cfg
-        self.mesh = mesh
+        # The runtime places every shard itself (shard_map, per-device
+        # staging), so it keeps the mesh's devices and axis names with
+        # automatic axis types: ``jax.make_mesh`` defaults to explicit
+        # ones, under which a heap update of unsharded rows is a type error.
+        self.mesh = (None if mesh is None
+                     else jax.sharding.Mesh(mesh.devices, mesh.axis_names))
         self.mesh_axis = mesh_axis
         self._cost_model = cost_model
         self.comms: list[Communicator] = []
@@ -761,7 +780,7 @@ class OcclRuntime:
         self._ensure_built()
         self._flush_staged()
         prev_slices = int(np.asarray(self._state.slices_moved).sum())
-        st = self.queues.pack_sq(self._state)
+        st = self.queues.pack_sq(self._state, self._staging.sharding)
         if tick_k is None:
             st = self._daemon(st)
         else:
@@ -939,7 +958,12 @@ class OcclRuntime:
         remap = {m: m - (m > dead) for m in range(R)}
         old_log = self._reg_log
         old_cids = list(self._log_cids)
-        self.cfg = dataclasses.replace(self.cfg, n_ranks=R - 1)
+        heap_elems = self.cfg.heap_elems
+        # A smaller ring can pad a chunk more than the old one did, so the
+        # log replays into open arenas; the heap keeps its size unless the
+        # replayed registrations need more.
+        self.cfg = dataclasses.replace(self.cfg, n_ranks=R - 1,
+                                       heap_elems=1 << 62)
         self.comms = []
         self.specs = []
         self._tail_of = {}
@@ -958,7 +982,6 @@ class OcclRuntime:
         self._prologue_jit = None
         self._device_api = None
         self._state = None
-        self.queues = HostQueues(self.cfg)
         self._outstanding = collections.defaultdict(collections.deque)
         self._submit_counts = {}
         self._generation += 1
@@ -1060,6 +1083,9 @@ class OcclRuntime:
                 new_log_cids.append(head)
         finally:
             self._replaying = False
+        self.cfg = dataclasses.replace(
+            self.cfg, heap_elems=max(heap_elems, self._in_ptr, self._out_ptr))
+        self.queues = HostQueues(self.cfg)
         self._reg_log = new_log
         self._log_cids = new_log_cids
         # --- 3. replay surviving wedged submissions ---------------------

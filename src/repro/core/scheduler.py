@@ -593,7 +593,7 @@ def lanes_step(cfg: OcclConfig, st: DaemonState, shared: SharedTables,
             flags[:, None, :], (L, B, 4)).reshape(L * B, 4)
         value = kops.fused_primitive_batch(
             recv_val.reshape(L * B, SL), in_val.reshape(L * B, SL),
-            flags_lb).reshape(L, B, SL)
+            flags_lb, interpret=cfg.pallas_interpret).reshape(L, B, SL)
     else:
         reduced = _combine_by_op(opv, recv_val, in_val)
         sel = lambda m: m[:, None, None]

@@ -24,6 +24,7 @@ import collections
 import dataclasses
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -106,11 +107,14 @@ class HostQueues:
         return items
 
     # -- device-bound packing ---------------------------------------------
-    def pack_sq(self, st: DaemonState) -> DaemonState:
+    def pack_sq(self, st: DaemonState, sharding=None) -> DaemonState:
         """Load up to sq_len pending SQEs per rank into the state's SQ and
         reset the cursors (the previous launch's consumed entries were
-        already popped by :meth:`reconcile`)."""
+        already popped by :meth:`reconcile`).  ``sharding`` (mesh backend)
+        places each rank's rows straight on its own device."""
         cfg = self.cfg
+        put = (jnp.asarray if sharding is None
+               else lambda a: jax.device_put(a, sharding))
         sq_coll = np.full((cfg.n_ranks, cfg.sq_len), -1, np.int32)
         sq_prio = np.zeros((cfg.n_ranks, cfg.sq_len), np.int32)
         sq_in = np.full((cfg.n_ranks, cfg.sq_len), -1, np.int32)
@@ -126,12 +130,12 @@ class HostQueues:
                 sq_out[r, i] = e.out_off
             sq_size[r] = n
         return st._replace(
-            sq_coll=jnp.asarray(sq_coll), sq_prio=jnp.asarray(sq_prio),
-            sq_in=jnp.asarray(sq_in), sq_out=jnp.asarray(sq_out),
-            sq_size=jnp.asarray(sq_size),
-            sq_read=jnp.zeros((cfg.n_ranks,), jnp.int32),
-            cq_coll=jnp.full((cfg.n_ranks, cfg.cq_len), -1, jnp.int32),
-            cq_count=jnp.zeros((cfg.n_ranks,), jnp.int32),
+            sq_coll=put(sq_coll), sq_prio=put(sq_prio),
+            sq_in=put(sq_in), sq_out=put(sq_out),
+            sq_size=put(sq_size),
+            sq_read=put(np.zeros((cfg.n_ranks,), np.int32)),
+            cq_coll=put(np.full((cfg.n_ranks, cfg.cq_len), -1, np.int32)),
+            cq_count=put(np.zeros((cfg.n_ranks,), np.int32)),
         )
 
     # -- post-launch reconciliation ----------------------------------------

@@ -103,8 +103,10 @@ def _stacked(merged) -> Optional[tuple]:
 @dataclasses.dataclass
 class _WritePlan:
     fn: Callable             # (heap, vals, gather_src, mask) -> heap
-    gather_src: jnp.ndarray  # device-resident, uploaded once per plan
-    mask: jnp.ndarray
+    # Device-resident, uploaded once per plan; None where ``fn`` does not
+    # gather (pad-free layouts) or the sharded path replaces it.
+    gather_src: Optional[jnp.ndarray]
+    mask: Optional[jnp.ndarray]
     # Sharded fast path (mesh backend): when the write set is one dense
     # full-rank stacked block, the packed payload is placed PER DEVICE via
     # jax.device_put with the heap's NamedSharding and the update runs
@@ -211,9 +213,15 @@ class StagingEngine:
             def sharded_fn(heap, block):
                 return jax.lax.dynamic_update_slice(heap, block, (0, s_off))
 
-        plan = _WritePlan(fn=fn, gather_src=jnp.asarray(src),
-                          mask=jnp.asarray(mask), sharded_fn=sharded_fn,
-                          src_np=src, mask_np=mask, identity=identity)
+        # Only the general gather reads the maps on the device: a sharded or
+        # pad-free plan leaves them on the host, so they never occupy the
+        # first device (as large as the write set itself, in int32).
+        on_device = sharded_fn is None and not identity
+        plan = _WritePlan(fn=fn,
+                          gather_src=jnp.asarray(src) if on_device else None,
+                          mask=jnp.asarray(mask) if on_device else None,
+                          sharded_fn=sharded_fn, src_np=src, mask_np=mask,
+                          identity=identity)
         if len(self._write_plans) > 64:    # evict least-recently-used
             self._write_plans.pop(next(iter(self._write_plans)))
         self._write_plans[sig] = plan

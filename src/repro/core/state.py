@@ -155,10 +155,15 @@ def init_state(cfg: OcclConfig, per_rank: bool = True,
     """Fresh state; leading rank axis added when ``per_rank``.
 
     ``sharding`` (mesh backend) is a ``NamedSharding`` placing the leading
-    rank axis on the mesh's rank axis: every [R, ...] leaf is device_put
-    per shard at creation, so the state is device-resident and sharded
-    BEFORE the first daemon launch or staging flush — no full-array
-    single-device hop on first use."""
+    rank axis on the mesh's rank axis: every [R, ...] leaf is created by
+    a jit with that output sharding, so each device materializes only its
+    own rank's rows — the state never passes through one device."""
+    if sharding is not None:
+        import jax
+
+        assert per_rank, "a sharded state carries the leading rank axis"
+        return jax.jit(lambda: init_state(cfg, per_rank=True),
+                       out_shardings=sharding)()
     C, K, L = cfg.max_colls, cfg.conn_depth, cfg.max_comms
     B = cfg.burst_slices
     SQL, CQL, H, SL = cfg.sq_len, cfg.cq_len, cfg.heap_elems, cfg.slice_elems
@@ -213,9 +218,4 @@ def init_state(cfg: OcclConfig, per_rank: bool = True,
                 for f, v in s._asdict().items()
             }
         )
-        if sharding is not None:
-            import jax
-
-            s = jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, sharding), s)
     return s

@@ -36,8 +36,11 @@ def _kernel(a_ref, b_ref, o_ref, *, op: int):
 
 @functools.partial(jax.jit, static_argnames=("op", "interpret"))
 def chunk_combine_pallas(a: jnp.ndarray, b: jnp.ndarray, op: int = 0, *,
-                         interpret: bool = True) -> jnp.ndarray:
-    """Elementwise combine of flat [T] buffers (T padded to TILE)."""
+                         interpret: bool = False) -> jnp.ndarray:
+    """Elementwise combine of flat [T] buffers (T padded to TILE).
+
+    ``interpret=True`` runs the Pallas interpreter (CPU callers and
+    tests); the default compiles the kernel for the TPU."""
     (T,) = a.shape
     pad = (-T) % TILE
     if pad:

@@ -9,10 +9,12 @@ pass through VMEM instead of separate reduce + copy kernels.
 Layout: payload/local are [N, S], where the scheduler batches the FULL
 superstep burst into N = L * burst_slices rows (every lane's contiguous
 slice burst) and S = slice_elems — one kernel call per superstep instead of
-one per lane per slice.  Grid is (N, S // TS); each program instance owns a
-(1, TS) VMEM tile.  The per-row opcode (recv, reduce, reads_in, op) rides
-in SMEM via a scalar BlockSpec.  TS is a multiple of 128 to keep tiles
-lane-aligned for the VPU (small-S test shapes fall back to S itself).
+one per lane per slice.  Grid is (N // RB, S // TS): each program instance
+owns an (RB, TS) VMEM tile, with RB = 8 rows when N is a multiple of 8 and
+all N rows otherwise, and TS a multiple of 128 dividing S (or S itself) —
+the TPU's (8, 128) tiling rule for the last two block dimensions.  The
+per-row opcodes (recv, reduce, reads_in, op) are scalar-prefetched into
+SMEM as one flat [N * 4] vector; each tile applies them row by row.
 """
 from __future__ import annotations
 
@@ -23,56 +25,61 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_ROWS = 8
 
-def _tile(s: int) -> int:
-    # Largest power-of-two tile <= 512 dividing S, floor 8 (interp-friendly).
-    for ts in (512, 256, 128, 64, 32, 16, 8):
+
+def _row_block(n: int) -> int:
+    return _ROWS if n % _ROWS == 0 else n
+
+
+def _lane_tile(s: int) -> int:
+    # Largest lane-aligned tile dividing S; S itself when none does.
+    for ts in (8192, 4096, 2048, 1024, 512, 256, 128):
         if s % ts == 0:
             return ts
     return s
 
 
-def _kernel(flags_ref, payload_ref, local_ref, out_ref):
-    recv = flags_ref[0, 0] > 0
-    reduce = flags_ref[0, 1] > 0
-    reads = flags_ref[0, 2] > 0
-    op = flags_ref[0, 3]
-
-    p = payload_ref[...]
-    l = local_ref[...]
-    # bf16 combines accumulate in f32 (matches ref oracle).
-    pf = p.astype(jnp.float32)
-    lf = l.astype(jnp.float32)
-    combined = jax.lax.switch(
-        jnp.clip(op, 0, 3),
-        [lambda x, y: x + y, jnp.maximum, jnp.minimum, lambda x, y: x * y],
-        pf, lf,
-    )
-    val = jnp.where(
-        reduce, combined,
-        jnp.where(recv, pf, jnp.where(reads, lf, jnp.zeros_like(lf))))
-    out_ref[...] = val.astype(out_ref.dtype)
+def _kernel(flags_ref, payload_ref, local_ref, out_ref, *, rows: int):
+    r0 = pl.program_id(0) * rows
+    for i in range(rows):
+        base = (r0 + i) * 4
+        recv = flags_ref[base] > 0
+        reduce = flags_ref[base + 1] > 0
+        reads = flags_ref[base + 2] > 0
+        op = flags_ref[base + 3]
+        # bf16 combines accumulate in f32 (matches the ref.py oracle).
+        pf = payload_ref[pl.ds(i, 1), :].astype(jnp.float32)
+        lf = local_ref[pl.ds(i, 1), :].astype(jnp.float32)
+        combined = jnp.where(
+            op == 0, pf + lf,
+            jnp.where(op == 1, jnp.maximum(pf, lf),
+                      jnp.where(op == 2, jnp.minimum(pf, lf), pf * lf)))
+        val = jnp.where(
+            reduce, combined,
+            jnp.where(recv, pf, jnp.where(reads, lf, jnp.zeros_like(lf))))
+        out_ref[pl.ds(i, 1), :] = val.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_primitive_pallas(payload: jnp.ndarray, local: jnp.ndarray,
                            flags: jnp.ndarray, *,
-                           interpret: bool = True) -> jnp.ndarray:
-    """payload, local: [B, S]; flags: [B, 4] i32 -> value [B, S]."""
-    B, S = payload.shape
-    TS = _tile(S)
-    grid = (B, S // TS)
+                           interpret: bool = False) -> jnp.ndarray:
+    """payload, local: [N, S]; flags: [N, 4] i32 -> value [N, S].
+
+    ``interpret=True`` runs the Pallas interpreter (CPU callers and
+    tests); the default compiles the kernel for the TPU."""
+    N, S = payload.shape
+    RB, TS = _row_block(N), _lane_tile(S)
+    tile = pl.BlockSpec((RB, TS), lambda b, s, flags: (b, s))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            # Per-row opcode in SMEM: one (1, 4) block per row program.
-            pl.BlockSpec((1, 4), lambda b, s: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, TS), lambda b, s: (b, s)),
-            pl.BlockSpec((1, TS), lambda b, s: (b, s)),
-        ],
-        out_specs=pl.BlockSpec((1, TS), lambda b, s: (b, s)),
-        out_shape=jax.ShapeDtypeStruct((B, S), payload.dtype),
+        functools.partial(_kernel, rows=RB),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // RB, S // TS),
+            in_specs=[tile, tile],
+            out_specs=tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, S), payload.dtype),
         interpret=interpret,
-    )(flags, payload, local)
+    )(flags.astype(jnp.int32).reshape(N * 4), payload, local)

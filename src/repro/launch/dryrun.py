@@ -122,11 +122,7 @@ def _lower_cell(cfg, cell, mesh):
 
     def mesh_ctx():
         # ambient mesh so P-only with_sharding_constraint resolves
-        # (jax.sharding.use_mesh was renamed set_mesh in jax 0.8)
-        try:
-            return jax.sharding.use_mesh(mesh)
-        except AttributeError:
-            return jax.sharding.set_mesh(mesh)
+        return jax.sharding.set_mesh(mesh)
     specs = input_specs(cfg, cell)
     bspecs = batch_pspecs(mesh, specs)
     to_sh = lambda tree: jax.tree_util.tree_map(

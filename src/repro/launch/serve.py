@@ -36,6 +36,9 @@ def main():
                     help="fabric size for the QoS collectives")
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from ..configs import get_config
     from ..serving.engine import Request, ServingEngine
 

@@ -35,6 +35,9 @@ def main():
                     help="simulated DP ranks for --occl-sync")
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from ..configs import get_config
     from ..configs.base import ShapeCell
     from ..data.pipeline import SyntheticPipeline
@@ -54,7 +57,7 @@ def main():
     print(f"arch={cfg.name} params={n:,}")
 
     if args.occl_sync:
-        run_occl_dp(cfg, cell, args)
+        run_occl_dp(cfg, cell, args.steps, dp=args.dp)
         return
 
     pipe = SyntheticPipeline(cfg, cell).start()
@@ -74,14 +77,23 @@ def main():
               f"{m['step_time_s']*1e3:7.1f} ms")
 
 
-def run_occl_dp(cfg, cell, args):
-    """Simulated DP training with OCCL gradient sync (paper Sec. 5.3)."""
+def run_occl_dp(cfg, cell, steps: int, dp: int = 2,
+                slice_elems: int = 256, burst_slices: int = 1,
+                on_step=None) -> dict:
+    """Simulated DP training with OCCL gradient sync (paper Sec. 5.3).
+
+    ``dp`` ranks share the one device; each draws its shard of ``cell``'s
+    global batch and their gradients are averaged through
+    :class:`~repro.train.occl_sync.OcclGradSync` with the given slice
+    size and burst width.  ``on_step(step, per_rank, synced)`` sees each
+    step's per-rank and synced gradient pytrees before the optimizer
+    applies them.  Returns ``{"losses", "supersteps", "sync"}``: the
+    rank-mean loss and the supersteps the sync took, per step."""
     from ..data.pipeline import SyntheticPipeline
     from ..train.occl_sync import OcclGradSync
     from ..train.state import init_state
     from ..train.step import make_apply_step, make_grads_step
 
-    dp = args.dp
     assert cell.global_batch % dp == 0
     states = [init_state(cfg) for _ in range(dp)]   # identical seeds
     pipes = [SyntheticPipeline(cfg, cell, shard_id=r, n_shards=dp)
@@ -89,24 +101,36 @@ def run_occl_dp(cfg, cell, args):
     grads_fn = jax.jit(make_grads_step(cfg))
     apply_fn = jax.jit(make_apply_step(cfg))
     gtmpl = jax.eval_shape(lambda: states[0].params)
-    sync = OcclGradSync(gtmpl, dp)
+    sync = OcclGradSync(gtmpl, dp, slice_elems=slice_elems,
+                        burst_slices=burst_slices)
 
-    for step in range(args.steps):
+    def clock() -> int:
+        return int(np.asarray(sync.occl.state.supersteps).max())
+
+    losses, supersteps = [], []
+    for step in range(steps):
         t0 = time.time()
         per_rank = []
-        losses = []
+        step_losses = []
         for r in range(dp):
             loss, g = grads_fn(states[r], next(pipes[r]))
             per_rank.append(g)
-            losses.append(float(loss))
+            step_losses.append(float(loss))
+        before = clock()
         synced = sync.all_reduce(per_rank)
+        supersteps.append(clock() - before)
+        if on_step is not None:
+            on_step(step, per_rank, synced)
         states = [apply_fn(states[r], synced[r]) for r in range(dp)]
-        print(f"step {step:3d} loss {np.mean(losses):.4f} "
+        losses.append(float(np.mean(step_losses)))
+        print(f"step {step:3d} loss {losses[-1]:.4f} "
               f"{(time.time()-t0)*1e3:7.1f} ms "
-              f"(occl launches={sync.occl.launches})")
+              f"(occl supersteps={supersteps[-1]} "
+              f"launches={sync.occl.launches})")
     st = sync.stats()
     print("occl grad-sync: supersteps", int(st["supersteps"].max()),
           "preempts", int(st["preempts"].sum()))
+    return {"losses": losses, "supersteps": supersteps, "sync": sync}
 
 
 if __name__ == "__main__":

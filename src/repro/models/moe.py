@@ -22,7 +22,7 @@ from .layers import ninit
 
 def _ep(x, spec):
     """Expert-parallel sharding constraint (REPRO_MOE_EP=1; needs an
-    ambient mesh — jax.sharding.use_mesh — else it is a no-op).  §Perf:
+    ambient mesh — jax.sharding.set_mesh — else it is a no-op).  §Perf:
     without it GSPMD all-gathers the full token array into every
     expert shard."""
     if os.environ.get("REPRO_MOE_EP") != "1":

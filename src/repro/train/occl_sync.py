@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import CollKind, OcclConfig, OcclRuntime, OrderPolicy
+from ..core import (CollKind, OcclConfig, OcclRuntime, OrderPolicy,
+                    registered_heap_elems)
 
 
 @dataclasses.dataclass
@@ -83,7 +84,6 @@ class OcclGradSync:
             buckets.append(Bucket(-1, cur_ids, cur_sizes, cur_total))
         self.buckets = buckets
 
-        heap = sum(2 * b.total + 64 * len(buckets) for b in buckets)
         self.compress_wire = compress_wire
         self.hierarchy = hierarchy
         if hierarchy is not None:
@@ -91,19 +91,16 @@ class OcclGradSync:
             assert G * N == n_ranks, (
                 f"hierarchy {hierarchy} does not tile {n_ranks} ranks")
         # A two-level bucket is a 3-stage chain: 3 collective slots per
-        # bucket, two lanes (all buckets share the derived intra and inter
-        # partitions; the logical group claims NO lane of its own), and
-        # intermediate heap regions (~2x per side).
+        # bucket and two lanes (all buckets share the derived intra and
+        # inter partitions; the logical group claims NO lane of its own).
         n_colls = len(buckets) * (3 if hierarchy is not None else 1)
-        self.occl = OcclRuntime(OcclConfig(
+        cfg = OcclConfig(
             n_ranks=n_ranks,
             max_colls=max(8, n_colls),
             max_comms=2 if hierarchy is not None else 1,
             slice_elems=slice_elems,
             conn_depth=max(8, 3 * burst_slices),
             burst_slices=burst_slices,
-            heap_elems=max(1 << 14, 4 * heap)
-                       * (2 if hierarchy is not None else 1),
             # In-step submission appends one SQE per bucket per rank into
             # the device SQ (no host pack_sq between them) — the SQ must
             # hold a whole step's buckets.
@@ -115,15 +112,24 @@ class OcclGradSync:
             bandwidth_groups=bandwidth_groups,
             intra_burst_cap=intra_burst_cap,
             inter_burst_cap=inter_burst_cap,
-        ))
-        comm = (self.occl.communicator(list(range(n_ranks)))
-                if hierarchy is None
-                else self.occl.logical_communicator(list(range(n_ranks))))
-        for b in buckets:
-            b.coll_id = self.occl.register(
-                CollKind.ALL_REDUCE, comm, n_elems=b.total,
-                algo="ring" if hierarchy is None else "two_level",
-                hierarchy=hierarchy)
+        )
+        # Each arena holds exactly what the bucket registrations take from
+        # it (padded chunk layouts and two-level intermediates included).
+        heap = registered_heap_elems(cfg, self._register)
+        self.occl = OcclRuntime(dataclasses.replace(cfg, heap_elems=heap))
+        for b, cid in zip(buckets, self._register(self.occl)):
+            b.coll_id = cid
+
+    def _register(self, rt: OcclRuntime) -> list:
+        """Register every bucket as one all-reduce on ``rt``."""
+        R = self.n_ranks
+        comm = (rt.communicator(list(range(R))) if self.hierarchy is None
+                else rt.logical_communicator(list(range(R))))
+        return [rt.register(CollKind.ALL_REDUCE, comm, n_elems=b.total,
+                            algo="ring" if self.hierarchy is None
+                            else "two_level",
+                            hierarchy=self.hierarchy)
+                for b in self.buckets]
 
     # ------------------------------------------------------------------
     def _pack(self, grads, bucket: Bucket) -> np.ndarray:

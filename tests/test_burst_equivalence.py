@@ -83,12 +83,14 @@ def test_burst_outputs_bit_identical_to_single_slice(policy, burst):
 def test_pallas_burst_path_end_to_end():
     """use_pallas=True routes the whole [L*B, SLICE] superstep burst
     through one fused_primitive_batch call; outputs must match the
-    jnp reference path exactly (both compute in f32)."""
+    jnp reference path exactly (both compute in f32).  Off the TPU the
+    kernel runs in the Pallas interpreter, asked for by name."""
     outs = {}
     for use_pallas in (False, True):
         cfg = OcclConfig(n_ranks=2, max_colls=4, max_comms=1, slice_elems=8,
                          conn_depth=6, burst_slices=4, heap_elems=1 << 13,
-                         use_pallas=use_pallas, superstep_budget=1 << 13)
+                         use_pallas=use_pallas, pallas_interpret=use_pallas,
+                         superstep_budget=1 << 13)
         rt = OcclRuntime(cfg)
         comm = rt.communicator([0, 1])
         cid = rt.register(CollKind.ALL_REDUCE, comm, n_elems=96)
