@@ -3,7 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows (common.row).
   Fig. 5  -> bench_overheads       Fig. 6/7 -> bench_collectives
   Sec 5.2 -> bench_deadlock        Fig. 8/10 -> bench_training
-  Fig. 9  -> bench_gang            Roofline  -> roofline (dry-run JSON)
+  Fig. 9  -> bench_gang
 
 ``--quick`` runs a CI-sized smoke (small sizes, 1 iter) that still
 rewrites BENCH_collectives.json — the burst sweep, the adversarial
@@ -115,14 +115,6 @@ def main(quick: bool = False) -> None:
     import bench_gang
     bench_gang.run()
     bench_training.run()
-    # roofline table (from cached dry-run artifacts, if present)
-    import roofline
-    rows = roofline.load()
-    for d in rows:
-        t = roofline.terms(d)
-        print(f"roofline/{d['arch']}_{d['cell']},"
-              f"{t['step_s']*1e6:.1f},"
-              f"dom={t['dominant']};mfu={t['mfu']*100:.1f}%")
 
 
 if __name__ == '__main__':

@@ -58,6 +58,7 @@ from .sqcq import SQE, HostQueues
 from .staging import StagingEngine
 from .state import DaemonState, init_state
 from .tables import StaticTables, build_tables
+from .trace import span
 
 
 def registered_heap_elems(cfg: OcclConfig,
@@ -611,33 +612,36 @@ class OcclRuntime:
         Returns ``{(rank, coll_id): logical output}`` as owned copies.
         Composite collectives read from their chain TAIL's output region
         but stay keyed by the logical (head) id the caller passed."""
-        self._ensure_built()
-        specs = self.specs
-        # Identical repeats dedup (pre-PR dict semantics); only CONFLICTING
-        # offsets for one (rank, coll_id) are ambiguous — the result dict
-        # could hold just one of them — and must be rejected.
-        resolved: dict = {}
-        orig_of: dict = {}
-        for e in reads:
-            cid = self._resolve_cid(e[1])
-            tcid = self._out_cid(cid)
-            off = (self._resolve_out_off(cid, e[2]) if len(e) > 2
-                   else specs[tcid].out_off)
-            prev = resolved.setdefault((e[0], tcid), off)
-            if prev != off:
-                raise ValueError(
-                    f"conflicting out_off reads for (rank={e[0]}, "
-                    f"coll={e[1]}): {prev} vs {off}; read each "
-                    "dynamic-offset result with its own read_output call")
-            orig_of.setdefault((e[0], tcid), []).append((e[0], e[1]))
-        keys = [(r, c, off) for (r, c), off in resolved.items()]
-        got = self._staging.read(self._state, keys)
-        out: dict = {}
-        for (r, tcid), v in got.items():
-            for i, okey in enumerate(dict.fromkeys(orig_of[(r, tcid)])):
-                # Every result stays an OWNED array even when a head and
-                # its tail were both requested (aliased reads get copies).
-                out[okey] = v if i == 0 else v.copy()
+        with span("read") as sp:
+            self._ensure_built()
+            specs = self.specs
+            # Identical repeats dedup (pre-PR dict semantics); only
+            # CONFLICTING offsets for one (rank, coll_id) are ambiguous —
+            # the result dict could hold just one of them — and must be
+            # rejected.
+            resolved: dict = {}
+            orig_of: dict = {}
+            for e in reads:
+                cid = self._resolve_cid(e[1])
+                tcid = self._out_cid(cid)
+                off = (self._resolve_out_off(cid, e[2]) if len(e) > 2
+                       else specs[tcid].out_off)
+                prev = resolved.setdefault((e[0], tcid), off)
+                if prev != off:
+                    raise ValueError(
+                        f"conflicting out_off reads for (rank={e[0]}, "
+                        f"coll={e[1]}): {prev} vs {off}; read each "
+                        "dynamic-offset result with its own read_output call")
+                orig_of.setdefault((e[0], tcid), []).append((e[0], e[1]))
+            keys = [(r, c, off) for (r, c), off in resolved.items()]
+            got = self._staging.read(self._state, keys)
+            out: dict = {}
+            for (r, tcid), v in got.items():
+                for i, okey in enumerate(dict.fromkeys(orig_of[(r, tcid)])):
+                    # Every result stays an OWNED array even when a head and
+                    # its tail were both requested (aliased reads get copies).
+                    out[okey] = v if i == 0 else v.copy()
+            sp.set_metadata(bytes=sum(v.nbytes for v in out.values()))
         return out
 
     def read_output(self, rank: int, coll_id: int,
@@ -677,62 +681,66 @@ class OcclRuntime:
         routed to the rank's ENTRY stage: a rank skipping the head would
         otherwise fetch a stage it is not a member of and stall the
         chain forever."""
-        self._ensure_built()
-        in_off_arg, out_off_arg = in_off, out_off
-        coll_id = self._resolve_cid(coll_id)
-        in_off = self._resolve_in_off(coll_id, in_off)
-        out_off = self._resolve_out_off(coll_id, out_off)
-        if data is not None:
-            # snapshot() validates and COPIES: the flush happens at the
-            # next launch prologue, and the pre-PR immediate-write
-            # semantics captured the value at call time — a caller
-            # reusing its buffer between submit and drive must not leak
-            # the mutation in.
-            self.queues.stage(rank, coll_id,
-                              self._staging.snapshot(coll_id, data), in_off)
-        entry = self._entry_of.get(coll_id, {}).get(rank, coll_id)
-        # This rank's completion endpoint (CQE source stage): its last
-        # participating stage — the logical tail except on chains that
-        # drop the rank early (e.g. tree-reduce non-leaders).
-        tcid = self._rank_tail.get(coll_id, {}).get(
-            rank, self._out_cid(coll_id))
-        cb = callback
-        if callback is not None and tcid != coll_id:
-            # CQEs of a chain are emitted by the rank's tail stage;
-            # surface the LOGICAL id to the user callback.
-            def cb(r, _c, _cb=callback, _lc=coll_id):
-                _cb(r, _lc)
-        # Outstanding-submission ledger (evict() replay + diagnose()):
-        # one record per SQE, popped by the accounting callback when the
-        # completion reconciles.  Payloads are NOT duplicated here —
-        # evict() recovers them from the staging queue or the device heap.
-        key = (rank, coll_id)
-        self._outstanding[key].append({
-            "seq": self._sub_seq, "rank": rank, "cid": coll_id,
-            "reg_index": self._head_to_reg.get(coll_id),
-            "prio": prio, "callback": callback,
-            "in_off_arg": in_off_arg, "out_off_arg": out_off_arg,
-            "in_off": in_off, "out_off": out_off,
-            "had_data": data is not None,
-        })
-        self._sub_seq += 1
-        self._submit_counts[key] = self._submit_counts.get(key, 0) + 1
+        with span("submit") as sp:
+            nbytes = 0
+            self._ensure_built()
+            in_off_arg, out_off_arg = in_off, out_off
+            coll_id = self._resolve_cid(coll_id)
+            in_off = self._resolve_in_off(coll_id, in_off)
+            out_off = self._resolve_out_off(coll_id, out_off)
+            if data is not None:
+                # snapshot() validates and COPIES: the flush happens at the
+                # next launch prologue, and the pre-PR immediate-write
+                # semantics captured the value at call time — a caller
+                # reusing its buffer between submit and drive must not leak
+                # the mutation in.
+                snap = self._staging.snapshot(coll_id, data)
+                nbytes = snap.nbytes
+                self.queues.stage(rank, coll_id, snap, in_off)
+            entry = self._entry_of.get(coll_id, {}).get(rank, coll_id)
+            # This rank's completion endpoint (CQE source stage): its last
+            # participating stage — the logical tail except on chains that
+            # drop the rank early (e.g. tree-reduce non-leaders).
+            tcid = self._rank_tail.get(coll_id, {}).get(
+                rank, self._out_cid(coll_id))
+            cb = callback
+            if callback is not None and tcid != coll_id:
+                # CQEs of a chain are emitted by the rank's tail stage;
+                # surface the LOGICAL id to the user callback.
+                def cb(r, _c, _cb=callback, _lc=coll_id):
+                    _cb(r, _lc)
+            # Outstanding-submission ledger (evict() replay + diagnose()):
+            # one record per SQE, popped by the accounting callback when the
+            # completion reconciles.  Payloads are NOT duplicated here —
+            # evict() recovers them from the staging queue or the device heap.
+            key = (rank, coll_id)
+            self._outstanding[key].append({
+                "seq": self._sub_seq, "rank": rank, "cid": coll_id,
+                "reg_index": self._head_to_reg.get(coll_id),
+                "prio": prio, "callback": callback,
+                "in_off_arg": in_off_arg, "out_off_arg": out_off_arg,
+                "in_off": in_off, "out_off": out_off,
+                "had_data": data is not None,
+            })
+            self._sub_seq += 1
+            self._submit_counts[key] = self._submit_counts.get(key, 0) + 1
 
-        def _acct(r, c, _key=key, _user=cb):
-            dq = self._outstanding.get(_key)
-            if dq:
-                dq.popleft()
-            if _user is not None:
-                _user(r, c)
+            def _acct(r, c, _key=key, _user=cb):
+                dq = self._outstanding.get(_key)
+                if dq:
+                    dq.popleft()
+                if _user is not None:
+                    _user(r, c)
 
-        # A non-head entry stage never reads the logical input (broadcast
-        # non-roots), so the head-resolved in_off override must not leak
-        # into its fetch — the entry keeps its registered default.
-        sqe_in = in_off if entry == coll_id else -1
-        self.queues.submit(rank, SQE(coll_id=entry, prio=prio,
-                                     in_off=sqe_in, out_off=out_off,
-                                     callback=_acct),
-                           cb_coll=tcid)
+            # A non-head entry stage never reads the logical input (broadcast
+            # non-roots), so the head-resolved in_off override must not leak
+            # into its fetch — the entry keeps its registered default.
+            sqe_in = in_off if entry == coll_id else -1
+            self.queues.submit(rank, SQE(coll_id=entry, prio=prio,
+                                         in_off=sqe_in, out_off=out_off,
+                                         callback=_acct),
+                               cb_coll=tcid)
+            sp.set_metadata(bytes=nbytes)
 
     def submit_all(self, coll_id: int, prio=0, data=None, callback=None,
                    in_off=-1, out_off=-1) -> None:
@@ -765,8 +773,10 @@ class OcclRuntime:
         since the previous launch."""
         staged = self.queues.take_staged()
         if staged:
-            self._state = self._staging.write(self._state, staged,
-                                              owned=True)
+            with span("flush", items=len(staged),
+                      bytes=sum(d.nbytes for _, _, d, _ in staged)):
+                self._state = self._staging.write(self._state, staged,
+                                                  owned=True)
 
     def launch_once(self, tick_k: Optional[int] = None) -> int:
         """One daemon launch; returns #CQEs drained (may be 0).
@@ -779,30 +789,31 @@ class OcclRuntime:
         """
         self._ensure_built()
         self._flush_staged()
-        prev_slices = int(np.asarray(self._state.slices_moved).sum())
-        st = self.queues.pack_sq(self._state, self._staging.sharding)
-        if tick_k is None:
-            st = self._daemon(st)
-        else:
-            if self._prologue_jit is None:
-                self._prologue_jit = jax.jit(launch_prologue)
-            tick = self.tick_fn(barrier=True)
-            st = self._prologue_jit(st)
-            while True:
-                st, flags = tick(st, jnp.int32(tick_k))
-                if not bool(jax.device_get(flags.live)):
-                    break
-        st = jax.block_until_ready(st)
-        self.launches += 1
-        self._state = st
-        fired = self.queues.reconcile(st)
-        self.launch_history.append({
-            "epoch": int(np.asarray(st.epoch).max()),
-            "launch_steps": int(np.asarray(st.launch_steps).max()),
-            "slices_moved": int(np.asarray(st.slices_moved).sum())
-                            - prev_slices,
-            "completions": fired,
-        })
+        with span("launch", tick_k=tick_k or 0):
+            prev_slices = int(np.asarray(self._state.slices_moved).sum())
+            st = self.queues.pack_sq(self._state, self._staging.sharding)
+            if tick_k is None:
+                st = self._daemon(st)
+            else:
+                if self._prologue_jit is None:
+                    self._prologue_jit = jax.jit(launch_prologue)
+                tick = self.tick_fn(barrier=True)
+                st = self._prologue_jit(st)
+                while True:
+                    st, flags = tick(st, jnp.int32(tick_k))
+                    if not bool(jax.device_get(flags.live)):
+                        break
+            st = jax.block_until_ready(st)
+            self.launches += 1
+            self._state = st
+            fired = self.queues.reconcile(st)
+            self.launch_history.append({
+                "epoch": int(np.asarray(st.epoch).max()),
+                "launch_steps": int(np.asarray(st.launch_steps).max()),
+                "slices_moved": int(np.asarray(st.slices_moved).sum())
+                                - prev_slices,
+                "completions": fired,
+            })
         return fired
 
     def drive(self, max_launches: int = 64,
@@ -1213,8 +1224,11 @@ class OcclRuntime:
             "launch_history": list(self.launch_history),
             # Staging-flush accounting (mesh fast path observability):
             # payload bytes shipped by StagingEngine.write and how many of
-            # those writes took the per-device sharded placement path.
+            # those writes took the per-device sharded placement path;
+            # plan_builds counts staging plans built on a cache miss (the
+            # span occl.plan_build marks each).
             "staging_flush_writes": self._staging.flush_writes,
+            "plan_builds": self._staging.plan_builds,
             "staging_flush_bytes": self._staging.flush_bytes,
             "staging_sharded_flushes": self._staging.sharded_flushes,
             # Flight-recorder export (core/recorder.py): per-rank event

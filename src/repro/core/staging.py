@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .config import OcclConfig
 from .state import DaemonState
 from .tables import StaticTables
@@ -153,6 +154,9 @@ class StagingEngine:
         self.flush_writes = 0
         self.flush_bytes = 0
         self.sharded_flushes = 0
+        # Write and read plans built on a cache miss (each compiles on its
+        # first call): 0 a step once the sizes have been seen.
+        self.plan_builds = 0
 
     # -- writes ----------------------------------------------------------
     def _write_plan(self, sig) -> _WritePlan:
@@ -163,6 +167,17 @@ class StagingEngine:
         if plan is not None:
             self._write_plans[sig] = plan    # touch: LRU re-insert
             return plan
+        self.plan_builds += 1
+        nbytes = sum(int(self.t.in_log[cid]) for _, cid, _ in sig) * \
+            self._dtype.itemsize
+        with trace.span("plan_build", kind="write", bytes=nbytes):
+            plan = self._build_write_plan(sig)
+        if len(self._write_plans) > 64:    # evict least-recently-used
+            self._write_plans.pop(next(iter(self._write_plans)))
+        self._write_plans[sig] = plan
+        return plan
+
+    def _build_write_plan(self, sig) -> _WritePlan:
         t = self.t
         segs, src, mask = [], [], []
         logical = 0
@@ -217,15 +232,11 @@ class StagingEngine:
         # pad-free plan leaves them on the host, so they never occupy the
         # first device (as large as the write set itself, in int32).
         on_device = sharded_fn is None and not identity
-        plan = _WritePlan(fn=fn,
+        return _WritePlan(fn=fn,
                           gather_src=jnp.asarray(src) if on_device else None,
                           mask=jnp.asarray(mask) if on_device else None,
                           sharded_fn=sharded_fn, src_np=src, mask_np=mask,
                           identity=identity)
-        if len(self._write_plans) > 64:    # evict least-recently-used
-            self._write_plans.pop(next(iter(self._write_plans)))
-        self._write_plans[sig] = plan
-        return plan
 
     def snapshot(self, coll_id: int, data) -> np.ndarray:
         """Validate one logical payload and return an OWNED heap-dtype
@@ -296,6 +307,17 @@ class StagingEngine:
         if plan is not None:
             self._read_plans[sig] = plan     # touch: LRU re-insert
             return plan
+        self.plan_builds += 1
+        nbytes = sum(int(self.t.out_span[cid]) for _, cid, _ in sig) * \
+            self._dtype.itemsize
+        with trace.span("plan_build", kind="read", bytes=nbytes):
+            plan = self._build_read_plan(sig)
+        if len(self._read_plans) > 64:     # evict least-recently-used
+            self._read_plans.pop(next(iter(self._read_plans)))
+        self._read_plans[sig] = plan
+        return plan
+
+    def _build_read_plan(self, sig) -> _ReadPlan:
         t = self.t
         segs, slot_by_key = [], {}
         pos = 0
@@ -322,11 +344,7 @@ class StagingEngine:
                 jax.lax.dynamic_slice(heap, (rank, off), (1, span)).ravel()
                 for rank, off, span in merged])
 
-        plan = _ReadPlan(fn=fn, slot_by_key=slot_by_key)
-        if len(self._read_plans) > 64:     # evict least-recently-used
-            self._read_plans.pop(next(iter(self._read_plans)))
-        self._read_plans[sig] = plan
-        return plan
+        return _ReadPlan(fn=fn, slot_by_key=slot_by_key)
 
     def read(self, state: DaemonState, keys) -> dict:
         """keys: iterable of ``(rank, coll_id, base_out_off)``.  Returns

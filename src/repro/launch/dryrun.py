@@ -18,7 +18,7 @@ For every (arch x shape-cell), lower + compile the train/prefill/serve
 step from ShapeDtypeStructs on the production mesh — 16x16 single-pod and
 2x16x16 multi-pod — and record memory_analysis / cost_analysis plus the
 collective-traffic breakdown parsed from the compiled HLO.  Results land
-in benchmarks/dryrun_results/*.json for the roofline harness.
+in benchmarks/dryrun_results/*.json, one file per (arch, cell, mesh).
 
 Usage:
     python -m repro.launch.dryrun --arch llama3-8b --cell train_4k

@@ -25,6 +25,7 @@ import numpy as np
 
 from ..core import (CollKind, OcclConfig, OcclRuntime, OrderPolicy,
                     registered_heap_elems)
+from ..core.trace import span
 
 
 @dataclasses.dataclass
@@ -133,12 +134,14 @@ class OcclGradSync:
 
     # ------------------------------------------------------------------
     def _pack(self, grads, bucket: Bucket) -> np.ndarray:
-        leaves = jax.tree_util.tree_leaves(grads)
-        parts = [np.asarray(leaves[i], np.float32).ravel()
-                 for i in bucket.leaf_ids]
-        out = np.concatenate(parts)
-        if self.compress_wire:
-            out = np.asarray(jnp.asarray(out, jnp.bfloat16))
+        with span("pack") as sp:
+            leaves = jax.tree_util.tree_leaves(grads)
+            parts = [np.asarray(leaves[i], np.float32).ravel()
+                     for i in bucket.leaf_ids]
+            out = np.concatenate(parts)
+            if self.compress_wire:
+                out = np.asarray(jnp.asarray(out, jnp.bfloat16))
+            sp.set_metadata(bytes=out.nbytes)
         return out
 
     # -- overlap-mode helpers (train/step.py custom_vjp boundaries) -------
@@ -177,21 +180,24 @@ class OcclGradSync:
             [(r, b.coll_id) for r in range(self.n_ranks)
              for b in self.buckets])
 
-        outs = []
-        for r in range(self.n_ranks):
-            leaves = [None] * len(self.shapes)
-            for b in self.buckets:
-                # read_outputs_bulk returns owned copies, so the average
-                # can be taken in place without corrupting sibling reads.
-                flat = np.asarray(reads[(r, b.coll_id)], np.float32)
-                flat /= self.n_ranks
-                off = 0
-                for i, n in zip(b.leaf_ids, b.sizes):
-                    leaves[i] = jnp.asarray(
-                        flat[off:off + n].reshape(self.shapes[i]),
-                        self.dtypes[i])
-                    off += n
-            outs.append(jax.tree_util.tree_unflatten(self.treedef, leaves))
+        with span("unpack", bytes=sum(v.nbytes for v in reads.values())):
+            outs = []
+            for r in range(self.n_ranks):
+                leaves = [None] * len(self.shapes)
+                for b in self.buckets:
+                    # read_outputs_bulk returns owned copies, so the
+                    # average can be taken in place without corrupting
+                    # sibling reads.
+                    flat = np.asarray(reads[(r, b.coll_id)], np.float32)
+                    flat /= self.n_ranks
+                    off = 0
+                    for i, n in zip(b.leaf_ids, b.sizes):
+                        leaves[i] = jnp.asarray(
+                            flat[off:off + n].reshape(self.shapes[i]),
+                            self.dtypes[i])
+                        off += n
+                outs.append(
+                    jax.tree_util.tree_unflatten(self.treedef, leaves))
         return outs
 
     def evict(self, rank: int) -> dict:
