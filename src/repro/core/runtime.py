@@ -1223,14 +1223,17 @@ class OcclRuntime:
             "launches": self.launches,
             "launch_history": list(self.launch_history),
             # Staging-flush accounting (mesh fast path observability):
-            # payload bytes shipped by StagingEngine.write and how many of
-            # those writes took the per-device sharded placement path;
-            # plan_builds counts staging plans built on a cache miss (the
-            # span occl.plan_build marks each).
+            # payload bytes shipped by StagingEngine.write, how many of
+            # those writes took the per-device sharded placement path and
+            # how many the element gather (in_perm layouts only; every
+            # other layout packs as contiguous run copies); plan_builds
+            # counts staging plans built on a cache miss (the span
+            # occl.plan_build marks each, with the plan's path).
             "staging_flush_writes": self._staging.flush_writes,
             "plan_builds": self._staging.plan_builds,
             "staging_flush_bytes": self._staging.flush_bytes,
             "staging_sharded_flushes": self._staging.sharded_flushes,
+            "staging_gather_flushes": self._staging.gather_flushes,
             # Flight-recorder export (core/recorder.py): per-rank event
             # ring + wrap-proof per-kind cumulative counters.  Decode with
             # ``recorder.events``; ``recorder.diagnose(runtime)`` names

@@ -10,25 +10,34 @@ index maps of :class:`repro.core.tables.StaticTables` (``stage_in_map`` /
 
 * **write**: ONE host->device transfer of the concatenated logical
   payloads; the pack transform into the padded chunk layout runs
-  on-device (a fused gather + mask when any write has pad positions —
-  pads are zero-filled as part of the same op, so stale heap data can
-  never leak into the padded slices of chunked collectives); the packed
-  segments then land in ``heap_in`` via buffer-donated device updates —
-  in place on backends that implement donation (CPU/GPU/TPU in current
-  jaxlibs), never a host heap mirror.
+  on-device, and zero-fills every pad of every written span as part of
+  the same program, so stale heap data can never leak into the padded
+  slices of chunked collectives.  At plan-build time each (rank,
+  collective) entry's map is split into RUNS: maximal stretches filled
+  from consecutive logical positions, and stretches of pads.  Where no
+  entry has more live runs than chunks (every chunked, ragged and
+  pad-free layout) the pack is static contiguous slices of the payload
+  and zero blocks — a copy, with no index arrays on the device.  Only
+  entries whose maps split finer (the ``in_perm`` granule transposes of
+  composite all-to-alls) keep the element gather over device-resident
+  maps.  The packed segments land in ``heap_in`` via buffer-donated
+  device updates — in place on backends that implement donation
+  (CPU/GPU/TPU in current jaxlibs), never a host heap mirror.
 * **read**: the mirror path out of ``heap_out``: device segment slices
   fused into one buffer, ONE device->host transfer, and a vectorized
   un-pad.  Results are owned writable copies (never views aliasing the
   heap snapshot), so callers may mutate them freely.
 
-Plans — the compiled program plus its device-resident index arrays — are
-cached by the (rank, collective, base-offset) signature of the write/read
-set, so a steady-state training step (identical buckets every iteration)
-compiles once and thereafter only ships payload values.  At plan-build
-time adjacent heap regions are COALESCED: the runtime's split in/out
-allocation arenas pack registered buffers contiguously, so a grad-sync
-step that stages every bucket collapses to a single stacked ``[R, W]``
-``dynamic_update_slice`` (write) / ``dynamic_slice`` (read) instead of
+Plans — the compiled program, plus the gather's device-resident index
+arrays where it has them — are cached by the (rank, collective,
+base-offset) signature of the write/read set, so a steady-state training
+step (identical buckets every iteration) compiles once and thereafter
+only ships payload values.  At plan-build time adjacent heap regions are
+COALESCED: the runtime's split in/out allocation arenas pack registered
+buffers contiguously, so a grad-sync step that stages every bucket
+collapses to one stacked ``[R, W]`` block — a single
+``dynamic_update_slice`` (write; a run plan stacks one packed row per
+rank) / ``dynamic_slice`` (read) instead of
 one op per (rank, collective).  Cost therefore scales with payload BYTES,
 not with heap size or Python chunk-loop iterations.
 
@@ -101,11 +110,103 @@ def _stacked(merged) -> Optional[tuple]:
     return None
 
 
+def _entry_runs(m: np.ndarray, span: int, n_chunks: int, lo: int):
+    """One entry's padded span as runs in position order: ``(n, j)`` for
+    ``n`` positions filled from logical positions ``j, j + 1, ...`` (``lo``
+    is the entry's first), ``(n, None)`` for ``n`` pads.  None where the
+    live map splits into more runs than the span has chunks (an
+    ``in_perm`` granule transpose): the entry then needs the gather."""
+    live = []
+    if m.size:
+        starts = np.flatnonzero(np.diff(m) != 1) + 1
+        if starts.size >= n_chunks:
+            return None
+        starts = np.concatenate(([0], starts))
+        sizes = np.diff(np.append(starts, m.size))
+        live = sorted(zip(m[starts].tolist(), sizes.tolist(),
+                          (lo + starts).tolist()))
+    runs, pos = [], 0
+    for off, n, j in live:
+        if off > pos:
+            runs.append((off - pos, None))
+        runs.append((n, j))
+        pos = off + n
+    if span > pos:
+        runs.append((span - pos, None))
+    return runs
+
+
+def _run_rows(t: StaticTables, sig) -> Optional[list]:
+    """The (rank, base)-sorted write set as one list of runs per rank, in
+    packed order, adjacent runs coalesced (pad onto pad, or live onto the
+    live run whose logical end it continues); None where any entry needs
+    the gather."""
+    rows, logical, last_rank = [], 0, None
+    for rank, cid, _ in sig:
+        m = t.stage_in_map[cid]
+        span = int(t.in_span[cid])
+        runs = _entry_runs(m, span, span // int(t.chunk_pad[cid]), logical)
+        if runs is None:
+            return None
+        if rank != last_rank:
+            rows.append([])
+            last_rank = rank
+        row = rows[-1]
+        for n, j in runs:
+            if row and (j is None) == (row[-1][1] is None) and (
+                    j is None or row[-1][1] + row[-1][0] == j):
+                row[-1] = (row[-1][0] + n, row[-1][1])
+            else:
+                row.append((n, j))
+        logical += m.size
+    return rows
+
+
+def _is_copy(rows) -> bool:
+    """The packed set IS the payload: live runs only, in logical order."""
+    pos = 0
+    for n, j in (run for row in rows for run in row):
+        if j != pos:
+            return False
+        pos += n
+    return True
+
+
+def _pack_runs(vals, runs, dtype):
+    """Runs as packed positions: static slices of the flat payload and
+    zero pads, concatenated."""
+    return jnp.concatenate([jnp.zeros(n, dtype) if j is None
+                            else vals[j:j + n] for n, j in runs])
+
+
+def _gather_maps(t: StaticTables, sig):
+    """The element gather's maps over the concatenated padded spans: the
+    logical source of each position, and whether it is live (pads are
+    zero-filled)."""
+    src, mask = [], []
+    logical = 0
+    for _, cid, _ in sig:
+        span = int(t.in_span[cid])
+        m = t.stage_in_map[cid]
+        s = np.zeros(span, np.int32)
+        s[m] = logical + np.arange(m.size, dtype=np.int32)
+        ok = np.zeros(span, bool)
+        ok[m] = True
+        src.append(s)
+        mask.append(ok)
+        logical += m.size
+    return np.concatenate(src), np.concatenate(mask)
+
+
 @dataclasses.dataclass
 class _WritePlan:
     fn: Callable             # (heap, vals, gather_src, mask) -> heap
-    # Device-resident, uploaded once per plan; None where ``fn`` does not
-    # gather (pad-free layouts) or the sharded path replaces it.
+    # Device-resident, uploaded once per plan, and only for the element
+    # gather (``path == "gather"``: an entry's live map splits into more
+    # runs than chunks, as ``in_perm`` transposes do).  None for run plans
+    # (static slices of ``vals`` and zero blocks, one run per chunk at
+    # most: chunked, ragged and pad-free layouts) and where the sharded
+    # path replaces ``fn``.
     gather_src: Optional[jnp.ndarray]
     mask: Optional[jnp.ndarray]
     # Sharded fast path (mesh backend): when the write set is one dense
@@ -115,9 +216,12 @@ class _WritePlan:
     # broadcast.  None when the engine has no sharding or the set is not
     # a full-rank block (the general plan stays correct on any backend).
     sharded_fn: Optional[Callable] = None   # (heap, block [R, span]) -> heap
-    src_np: Optional[np.ndarray] = None     # host copies for host-side pack
+    # Host copies of the gather's maps, for the sharded path's host-side
+    # pack; None on run plans, and on a sharded set that is the payload
+    # itself (pad-free, in logical order).
+    src_np: Optional[np.ndarray] = None
     mask_np: Optional[np.ndarray] = None
-    identity: bool = False
+    path: str = "gather"    # "runs" | "gather" | "sharded"
 
 
 @dataclasses.dataclass
@@ -154,6 +258,9 @@ class StagingEngine:
         self.flush_writes = 0
         self.flush_bytes = 0
         self.sharded_flushes = 0
+        # Writes that took the element gather (an ``in_perm`` layout):
+        # every other layout packs as contiguous run copies.
+        self.gather_flushes = 0
         # Write and read plans built on a cache miss (each compiles on its
         # first call): 0 a step once the sizes have been seen.
         self.plan_builds = 0
@@ -170,8 +277,9 @@ class StagingEngine:
         self.plan_builds += 1
         nbytes = sum(int(self.t.in_log[cid]) for _, cid, _ in sig) * \
             self._dtype.itemsize
-        with trace.span("plan_build", kind="write", bytes=nbytes):
+        with trace.span("plan_build", kind="write", bytes=nbytes) as sp:
             plan = self._build_write_plan(sig)
+            sp.set_metadata(path=plan.path)
         if len(self._write_plans) > 64:    # evict least-recently-used
             self._write_plans.pop(next(iter(self._write_plans)))
         self._write_plans[sig] = plan
@@ -179,43 +287,12 @@ class StagingEngine:
 
     def _build_write_plan(self, sig) -> _WritePlan:
         t = self.t
-        segs, src, mask = [], [], []
-        logical = 0
-        for rank, cid, base in sig:
-            span = int(t.in_span[cid])
-            m = t.stage_in_map[cid]
-            s = np.zeros(span, np.int32)
-            s[m] = logical + np.arange(m.size, dtype=np.int32)
-            ok = np.zeros(span, bool)
-            ok[m] = True
-            src.append(s)
-            mask.append(ok)
-            segs.append((int(rank), int(base), span))
-            logical += m.size
-        src = np.concatenate(src)
-        mask = np.concatenate(mask)
-        # Pad-free layouts in sorted order: logical order IS packed order.
-        identity = bool(mask.all()) and bool(
-            (src == np.arange(src.size, dtype=np.int32)).all())
+        segs = [(int(rank), int(base), int(t.in_span[cid]))
+                for rank, cid, base in sig]
         merged = _merge_segments(segs)
         stack = _stacked(merged)
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def fn(heap, vals, gather_src, ok):
-            packed = vals if identity else jnp.where(ok, vals[gather_src], 0)
-            packed = packed.astype(heap.dtype)
-            if stack is not None:
-                r0, off, span = stack
-                block = packed.reshape(-1, span)
-                heap = jax.lax.dynamic_update_slice(heap, block, (r0, off))
-            else:
-                o = 0
-                for rank, off, span in merged:
-                    heap = jax.lax.dynamic_update_slice(
-                        heap, packed[o:o + span][None, :], (rank, off))
-                    o += span
-            return heap
-
+        # One row of runs per rank; None where an entry needs the gather.
+        rows = _run_rows(t, sig)
         # Sharded fast path: a dense block covering EVERY rank with one
         # identical column window (the grad-sync / all-ranks-submit shape)
         # updates shard-locally after per-device payload placement.
@@ -228,15 +305,49 @@ class StagingEngine:
             def sharded_fn(heap, block):
                 return jax.lax.dynamic_update_slice(heap, block, (0, s_off))
 
-        # Only the general gather reads the maps on the device: a sharded or
-        # pad-free plan leaves them on the host, so they never occupy the
-        # first device (as large as the write set itself, in int32).
-        on_device = sharded_fn is None and not identity
+        path = ("sharded" if sharded_fn is not None else
+                "runs" if rows is not None else "gather")
+        # The sharded host pack needs the maps unless the set is the
+        # payload itself (pad-free, in logical order).
+        src = mask = None
+        if path == "gather" or (path == "sharded" and not (
+                rows is not None and _is_copy(rows))):
+            src, mask = _gather_maps(t, sig)
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def fn(heap, vals, gather_src, ok):
+            vals = vals.astype(heap.dtype)
+            if stack is not None:
+                if rows is None:
+                    block = jnp.where(ok, vals[gather_src], 0).reshape(
+                        -1, stack[2])
+                else:
+                    # One packed row per rank, stacked: no reshape of the
+                    # flat payload (a relayout pass on the TPU).
+                    block = jnp.stack([_pack_runs(vals, row, heap.dtype)
+                                       for row in rows])
+                return jax.lax.dynamic_update_slice(heap, block, stack[:2])
+            if rows is None:
+                packed = jnp.where(ok, vals[gather_src], 0)
+            else:
+                packed = _pack_runs(vals, [run for row in rows for run in row],
+                                    heap.dtype)
+            o = 0
+            for rank, off, span in merged:
+                heap = jax.lax.dynamic_update_slice(
+                    heap, packed[o:o + span][None, :], (rank, off))
+                o += span
+            return heap
+
+        # Only the element gather reads the maps on the device; the sharded
+        # path packs on the host from them.  Run and pad-free plans upload
+        # nothing (the maps are as large as the write set itself, in int32).
+        on_device = path == "gather"
         return _WritePlan(fn=fn,
                           gather_src=jnp.asarray(src) if on_device else None,
                           mask=jnp.asarray(mask) if on_device else None,
                           sharded_fn=sharded_fn, src_np=src, mask_np=mask,
-                          identity=identity)
+                          path=path)
 
     def snapshot(self, coll_id: int, data) -> np.ndarray:
         """Validate one logical payload and return an OWNED heap-dtype
@@ -285,8 +396,8 @@ class StagingEngine:
             # rows, and the donated update runs shard-locally (the
             # sim-style path would commit the whole payload to one device
             # and let SPMD re-distribute it).
-            packed = vals if plan.identity else vals[plan.src_np]
-            if not plan.identity:
+            packed = vals if plan.src_np is None else vals[plan.src_np]
+            if plan.src_np is not None:
                 packed[~plan.mask_np] = packed.dtype.type(0)
             block = jax.device_put(
                 packed.reshape(self.cfg.n_ranks, -1), self.sharding)
@@ -297,6 +408,7 @@ class StagingEngine:
         # inside the one dispatch (zero-copy on CPU; one heap-width H2D
         # transfer on accelerators).
         heap = plan.fn(state.heap_in, vals, plan.gather_src, plan.mask)
+        self.gather_flushes += plan.path == "gather"
         return state._replace(heap_in=heap)
 
     # -- reads -----------------------------------------------------------

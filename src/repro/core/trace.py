@@ -22,8 +22,9 @@ The six phases of a grad-sync step are siblings and never nest:
 * ``occl.unpack`` (``bytes``): ``OcclGradSync`` divides, reshapes and
   uploads every leaf of every rank.
 
-``occl.plan_build`` (``kind`` ``write``/``read``, ``bytes``) nests inside a
-flush or a read: a staging plan missed its cache and was built (its index
+``occl.plan_build`` (``kind`` ``write``/``read``, ``bytes``; a write adds
+``path``: ``runs``, ``gather`` or ``sharded``) nests inside a flush or a
+read: a staging plan missed its cache and was built (its runs or index
 maps, their upload); the plan's first call, in the enclosing span,
 compiles it.
 

@@ -348,3 +348,134 @@ def test_reduce_op_with_staged_inputs():
     for r in range(2):
         np.testing.assert_allclose(rt.read_output(r, cid),
                                    np.maximum(xs[0], xs[1]), rtol=1e-6)
+
+
+# -- write plans: contiguous run copies against the element gather ---------
+
+def _reference_heap(rt, heap, writes):
+    """Each write's ``np.where(mask, vals[src], 0)`` over its padded span,
+    scattered into ``heap`` in (rank, offset) order: the element gather,
+    kept here as the reference every write plan matches bit for bit."""
+    t = rt._tables
+    heap = heap.copy()
+    for rank, cid, data, off in sorted(writes, key=lambda w: (w[0], w[3])):
+        span = int(t.in_span[cid])
+        m = t.stage_in_map[cid]
+        src = np.zeros(span, np.int64)
+        mask = np.zeros(span, bool)
+        src[m] = np.arange(m.size)
+        mask[m] = True
+        vals = np.asarray(data).astype(heap.dtype)
+        heap[rank, off:off + span] = np.where(mask, vals[src],
+                                              heap.dtype.type(0))
+    return heap
+
+
+def _write_case(case):
+    """(runtime, writes as (rank, cid, data, in_off), expected plan path)
+    of one layout the write plan has to pack."""
+    R = 4
+    cfg = {"bf16_heap": dict(dtype="bfloat16"),
+           "in_perm_all_to_all": dict(max_comms=3)}.get(case, {})
+    rt = OcclRuntime(_cfg(**cfg))
+    comm = rt.communicator(list(range(R)))
+    if case == "in_perm_all_to_all":
+        # The two-level plan's granule transposes: the head stage's
+        # input map splits into more runs than it has chunks.
+        cids = [rt.register(CollKind.ALL_TO_ALL, comm, n_elems=32,
+                            algo="two_level", hierarchy=(2, 2))]
+    else:
+        kinds = {"ceil_chunk_all_reduce": [(CollKind.ALL_REDUCE, 53)],
+                 "pad_free_identity": [(CollKind.BROADCAST, 32)],
+                 "flat_all_to_all": [(CollKind.ALL_TO_ALL, 54)],
+                 "ragged_zero_chunk": [(CollKind.ALL_TO_ALL_RAGGED, 9)],
+                 "bf16_heap": [(CollKind.ALL_REDUCE, 53)],
+                 "offset_overrides": [(CollKind.ALL_REDUCE, 33),
+                                      (CollKind.ALL_REDUCE, 33)],
+                 # Same padded span, different live tails: one dense
+                 # block whose rows are not alike.
+                 "stacked_unlike_rows": [(CollKind.ALL_REDUCE, 53),
+                                         (CollKind.ALL_REDUCE, 50)],
+                 "all_rank_stack": [(CollKind.ALL_REDUCE, 53),
+                                    (CollKind.ALL_GATHER, 21),
+                                    (CollKind.REDUCE_SCATTER, 37)]}[case]
+        cids = [_register(rt, kind, comm, n) for kind, n in kinds]
+    rt._ensure_built()
+    t = rt._tables
+    rng = np.random.RandomState(sum(map(ord, case)))
+    writes = []
+    for cid in cids[:1] if case == "offset_overrides" else cids:
+        for r in range(R):
+            off = rt.specs[cid].in_off
+            if case == "offset_overrides" and r % 2:
+                off = rt.specs[cids[1]].in_off    # the twin region
+            if case == "stacked_unlike_rows":
+                # Even ranks write the first, odd ranks the second, both
+                # into the first's region.
+                if (r % 2) != (cid == cids[1]):
+                    continue
+                off = rt.specs[cids[0]].in_off
+            writes.append((r, cid, rng.randn(int(t.in_log[cid])).astype(
+                np.float32), off))
+    if case == "ragged_zero_chunk":
+        assert 0 in rt.specs[cids[0]].chunk_sizes
+    path = "gather" if case == "in_perm_all_to_all" else "runs"
+    return rt, writes, path
+
+
+@pytest.mark.parametrize("case", [
+    "ceil_chunk_all_reduce", "pad_free_identity", "flat_all_to_all",
+    "ragged_zero_chunk", "bf16_heap", "offset_overrides",
+    "stacked_unlike_rows", "all_rank_stack", "in_perm_all_to_all"])
+def test_write_plan_matches_gather_reference_bitwise(case):
+    """Over a polluted heap, a bulk write leaves the heap bit-identical to
+    the numpy element gather: live positions copied, every pad of the
+    written spans zero, the rest of the heap untouched.  Only the in_perm
+    layout takes the gather, with device maps; every other layout packs
+    as run copies and uploads none."""
+    rt, writes, path = _write_case(case)
+    _pollute(rt)
+    before = np.asarray(rt.state.heap_in)
+    rt.write_inputs_bulk({(r, cid): (data, int(off))
+                          for r, cid, data, off in writes})
+    got = np.asarray(rt.state.heap_in)
+    want = _reference_heap(rt, before, writes)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    (plan,) = rt._staging._write_plans.values()
+    assert plan.path == path
+    assert (plan.gather_src is None and plan.mask is None) == (
+        path != "gather")
+    assert rt.stats()["staging_gather_flushes"] == (path == "gather")
+
+
+def test_grad_sync_write_set_builds_a_run_plan():
+    """The grad-sync shape (every rank stages every bucket, uneven chunk
+    tails) packs as run copies on every step: no device maps, no write
+    through the gather, and the mean is exact."""
+    from repro.train.occl_sync import OcclGradSync
+
+    tmpl = {"a": np.zeros((5, 7), np.float32),
+            "b": np.zeros(13, np.float32),
+            "c": np.zeros((3, 11), np.float32),
+            "d": np.zeros(17, np.float32)}
+    sync = OcclGradSync(tmpl, n_ranks=2, bucket_elems=40, slice_elems=8)
+    sync.occl._ensure_built()
+    t = sync.occl._tables
+    assert len(sync.buckets) > 1 and any(b.total % 2 for b in sync.buckets)
+    assert any(int(t.in_span[b.coll_id]) > b.total for b in sync.buckets)
+    rng = np.random.RandomState(3)
+    for _ in range(2):
+        grads = [{k: rng.randn(*v.shape).astype(np.float32)
+                  for k, v in tmpl.items()} for _ in range(2)]
+        outs = sync.all_reduce(grads)
+        for k in tmpl:
+            want = (grads[0][k] + grads[1][k]) / 2
+            for r in range(2):
+                np.testing.assert_array_equal(np.asarray(outs[r][k]), want)
+    plans = list(sync.occl._staging._write_plans.values())
+    assert plans and all(p.path == "runs" and p.gather_src is None
+                         and p.mask is None for p in plans)
+    st = sync.stats()
+    assert st["staging_flush_writes"] == 2
+    assert st["staging_gather_flushes"] == 0
