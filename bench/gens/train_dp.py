@@ -275,3 +275,49 @@ def check(job: Job) -> tuple:
     return (compare(readings(job), ref, job.tr["limits"]),
             {"reference_losses": ref["losses"].tolist(),
              "reference_first_grad_norm": ref["first_gnorm"]})
+
+
+def _values(checks) -> dict:
+    return {c["name"]: c["value"] for c in checks}
+
+
+def calibrate(job: Job, control: bool) -> dict:
+    """The readings the limits are set from (``bench/calibrate.py``): the
+    program's after its first steps and, with ``control``, the reference
+    computed one precision lower (fp8 products for bf16 training) and the
+    faults planted in the reference (half the batch left out, which on two
+    ranks is also each rank keeping its own gradient; one token altered
+    where the rows are made; the ranks' gradients summed, not averaged;
+    the state left unchanged), each put in the program's place."""
+    lim = job.tr["limits"]
+    release(job)
+    ref = reference(job)
+    out = {"program": _values(compare(readings(job), ref, lim)),
+           "reference_first_grad_norm": ref["first_gnorm"]}
+    if control:
+        def as_prog(r):
+            return {"losses": r["losses"], "first_grad": [r["first_grad"]],
+                    "change": [r["change"]]}
+        out["control_fp8"] = _values(compare(
+            as_prog(reference(job, fp8=True)), ref, lim))
+        half = [(t[:t.shape[0] // 2], g[:g.shape[0] // 2])
+                for t, g in job.batches]
+        out["fault_half_batch"] = _values(compare(
+            as_prog(reference(job, batches=half)), ref, lim))
+        altered = [(t.copy(), g) for t, g in job.batches]
+        for t, _ in altered:
+            t[0, 0] = (t[0, 0] + 1) % job.dims["vocab_size"]
+        out["fault_token"] = _values(compare(
+            as_prog(reference(job, batches=altered)), ref, lim))
+        # A sync that sums the ranks' gradients instead of averaging
+        # them: the optimizer gets dp times the gradient; its clipping
+        # and Adam's normalisation leave the losses and the change.
+        out["fault_sum_not_mean"] = _values(compare(
+            {"losses": ref["losses"],
+             "first_grad": [job.tr["dp"] * ref["first_grad"]],
+             "change": [ref["change"]]}, ref, lim))
+        out["fault_state_unchanged"] = _values(compare(
+            {"losses": np.repeat(ref["losses"][:1], len(ref["losses"])),
+             "first_grad": [ref["first_grad"]],
+             "change": [np.zeros_like(ref["change"])]}, ref, lim))
+    return out

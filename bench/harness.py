@@ -7,6 +7,19 @@ cell's configuration (``bench/configs/<config>.json``), its traffic mix
 ``bench/gens/<kind>.py``) and its metrics (``bench/metrics/<metric>.py``,
 each with ``read(ctx) -> float | None``).  A later cell, mix or metric is
 new files plus new entries; no file here changes.
+
+A generator of a new kind supplies ``setup(ctx) -> job``,
+``window(job, seconds=, units=)``, ``counters(job, win)``, ``work(job)``,
+``check(job) -> (checks, info)`` and ``calibrate(job, control) -> dict``:
+the numbers compared under ``program`` and, with ``control``, each of its
+controls under ``control_<name>`` and each planted fault under
+``fault_<name>``, every entry a dict of the numbers that ``check``
+compares.  ``bench/calibrate.py`` prints what it returns, and the control
+test asks that the program stays inside every limit while a ``control_*``
+entry exceeds one.  A cell on four chips rehearses and calibrates on four
+CPU devices.  A cell is appended to the ``workloads`` list of each
+end-to-end metric it reports that lists its cells (today
+``train_tokens_per_s``).
 """
 from __future__ import annotations
 
